@@ -91,8 +91,9 @@ def check_hbm_budget(model_cfg, cfg: Config, dtype, n_devices: int) -> dict:
     in-tree accounting).
 
     Returns the accounting dict (bytes, per device); raises ValueError
-    when over budget. Skips silently when the backend exposes no memory
-    stats (CPU tests).
+    when over budget. The CPU backend exposes no memory stats and skips
+    the check (tests); on a TPU a missing ``bytes_limit`` is an error,
+    never a skipped check.
 
     Sharding facts the math encodes (parallel/sharding.py): weights
     shard over "tp" only (each dp replica holds a full copy); the KV
@@ -103,11 +104,13 @@ def check_hbm_budget(model_cfg, cfg: Config, dtype, n_devices: int) -> dict:
     """
     import jax
 
-    try:
-        stats = jax.local_devices()[0].memory_stats() or {}
-        limit = stats.get("bytes_limit")
-    except Exception:
-        limit = None
+    dev = jax.local_devices()[0]
+    limit = (dev.memory_stats() or {}).get("bytes_limit")
+    if not limit and jax.default_backend() == "tpu":
+        raise RuntimeError(
+            f"{dev} reports no memory_stats()['bytes_limit']: the HBM "
+            "budget check cannot run, and serving without it trades a "
+            "named startup error for a device OOM mid-load")
     dsize = jnp.dtype(dtype).itemsize
     tp = max(1, cfg.tp_size)
     m = model_cfg
@@ -253,14 +256,33 @@ def build_engine(cfg: Config) -> EngineBase:
     # executables reload from disk on repeat starts of the same config.
     from fasttalk_tpu.utils.compile_cache import enable_compilation_cache
 
-    enable_compilation_cache(cfg.compile_cache, cfg.model_path)
+    enable_compilation_cache(cfg.compile_cache)
     # Multi-host: bring up the JAX distributed runtime (DCN) before any
     # device use so meshes can span every host. No-op outside a
-    # configured/pod environment. Lives here (not in the CLI) so bench,
-    # `main.py test`, and library users all inherit it.
+    # configured multi-host environment. Lives here (not in the CLI) so
+    # bench, `main.py test`, and library users all inherit it.
     from fasttalk_tpu.parallel.distributed import maybe_initialize
 
     maybe_initialize()
+    # The first backend touch of the process. An explicit COMPUTE_DEVICE
+    # that is not available raises ComputeDeviceError here, before any
+    # allocation — the engine never comes up on a device it was not
+    # asked for.
+    import jax
+
+    from fasttalk_tpu.utils.config import (ComputeDeviceError,
+                                           detect_compute_device)
+
+    device = detect_compute_device(cfg.compute_device)
+    if device == "tpu" and jax.default_backend() != "tpu":
+        raise ComputeDeviceError(
+            f"compute device resolved to 'tpu' but JAX's default "
+            f"backend is {jax.default_backend()!r}: arrays would be "
+            f"placed off the chip")
+    log.info(f"compute device: {device} (requested "
+             f"{cfg.compute_device}); JAX backend "
+             f"{jax.default_backend()}, {jax.device_count()} device(s), "
+             f"kind {jax.devices()[0].device_kind}")
     model_cfg = get_model_config(cfg.model_name, cfg.model_path)
     dtype = _DTYPES.get(cfg.dtype, jnp.bfloat16)
     acct = check_hbm_budget(model_cfg, cfg, dtype,
@@ -284,8 +306,6 @@ def build_engine(cfg: Config) -> EngineBase:
     weight_quant = _effective_weight_quant(cfg)
     if weight_quant in ("int8", "int4"):
         from fasttalk_tpu.ops.quant import quantizing_put
-
-        import jax
 
         if put is None:
             put = lambda arr, path: jax.device_put(jnp.asarray(arr, dtype))  # noqa: E731

@@ -106,33 +106,20 @@ def program_key(kind: str, **attrs: Any) -> str:
     return kind + "".join(f" {k}={attrs[k]}" for k in sorted(attrs))
 
 
-def _detect_peak(table) -> tuple[float, str]:
-    """(summed per-device peak from ``table``, device kind). 0.0 when
-    the platform has no table entry — the figure then reports null."""
-    try:
-        import jax
-
-        devs = jax.local_devices()
-    except Exception:
+def _detect_peak(table, devices) -> tuple[float, str]:
+    """(peak from ``table`` summed over ``devices``, device kind).
+    ``devices`` are the ones the engine computes on (one, or its
+    mesh's) — never every device of the host: a one-chip engine on a
+    four-chip host must not get a 4x denominator. 0.0 when the kind has
+    no table entry — the figure then reports null."""
+    if not devices:
         return 0.0, "unknown"
-    if not devs:
-        return 0.0, "unknown"
-    kind = getattr(devs[0], "device_kind", "") or devs[0].platform
+    kind = getattr(devices[0], "device_kind", "") or devices[0].platform
     low = str(kind).lower()
     for key, peak in table:
         if key in low:
-            return peak * len(devs), str(kind)
+            return peak * len(devices), str(kind)
     return 0.0, str(kind)
-
-
-def detect_peak_tflops() -> tuple[float, str]:
-    """(peak bf16 TFLOP/s per local device set, device kind)."""
-    return _detect_peak(PEAK_TFLOPS_BF16)
-
-
-def detect_peak_hbm_gbps() -> tuple[float, str]:
-    """(peak HBM GB/s per local device set, device kind)."""
-    return _detect_peak(PEAK_HBM_GBPS)
 
 
 class PerfLedger:
@@ -149,12 +136,14 @@ class PerfLedger:
         self.idle_gap_ms = idle_gap_ms if idle_gap_ms is not None \
             else max(0.0, env_float("PERF_IDLE_GAP_MS",
                                     DEFAULT_IDLE_GAP_MS))
-        # 0 = detect from the device kind lazily (first report).
+        # 0 = detect from the kind and count of the devices the engine
+        # computes on (bind_model), lazily at the first report.
         self._peak_override = peak_tflops if peak_tflops is not None \
             else env_float("PERF_PEAK_TFLOPS", 0.0)
         self._peak: tuple[float, str] | None = None
         self._hbm_override = env_float("PERF_PEAK_HBM_GBPS", 0.0)
         self._hbm_detected: tuple[float, str] | None = None
+        self._devices: list = []
         self._tracer = tracer
         # The continuous stack sampler (observability/profiler.py):
         # supplies engine-thread cause observations and GC pause
@@ -292,7 +281,8 @@ class PerfLedger:
                    dtype: str = "", kv_quant: str = "none",
                    kv_row_bytes: int = 0, weight_quant: str = "off",
                    weight_bytes_per_step: int = 0,
-                   attention_kernel: str = "") -> None:
+                   attention_kernel: str = "",
+                   devices: Any = ()) -> None:
         """Attach the served model's cost estimate (engine __init__).
         FLOPs/token = 2·params (every weight partakes in one multiply-
         accumulate) + 4·layers·q_dim·kv_len (QKᵀ and A·V per head).
@@ -305,8 +295,12 @@ class PerfLedger:
         ``attention_kernel``: which decode attention path the engine
         routes steps through (xla_dense / xla_gather / pallas_dense /
         pallas_paged) — pure attribution, so the README perf table and
-        docs/ROOFLINE.md can name the kernel per measured row."""
+        docs/ROOFLINE.md can name the kernel per measured row.
+        ``devices``: the devices the engine computes on (one, or its
+        mesh's); their kind and count size the roofline peaks."""
         with self._lock:
+            self._devices = list(devices)
+            self._peak = self._hbm_detected = None
             self._model_name = getattr(model_cfg, "name", "")
             self._num_slots = num_slots
             self._dtype = dtype
@@ -361,14 +355,16 @@ class PerfLedger:
         if self._peak_override > 0:
             return self._peak_override, "PERF_PEAK_TFLOPS"
         if self._peak is None:
-            self._peak = detect_peak_tflops()
+            self._peak = _detect_peak(PEAK_TFLOPS_BF16,
+                                      self._devices)
         return self._peak
 
     def _peak_hbm(self) -> tuple[float, str]:
         if self._hbm_override > 0:
             return self._hbm_override, "PERF_PEAK_HBM_GBPS"
         if self._hbm_detected is None:
-            self._hbm_detected = detect_peak_hbm_gbps()
+            self._hbm_detected = _detect_peak(PEAK_HBM_GBPS,
+                                              self._devices)
         return self._hbm_detected
 
     def report(self, now: float | None = None) -> dict[str, Any]:

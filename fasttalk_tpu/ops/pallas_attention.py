@@ -56,6 +56,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from fasttalk_tpu.ops.pallas_backend import resolve_interpret
+
 _NEG_INF = -1e30
 
 
@@ -178,8 +180,7 @@ def decode_attend(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     g = nq // nkv
     if s % block_size:
         raise ValueError(f"cache bucket {s} not divisible by {block_size}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     nb = s // block_size
     qg = _pack_q(q, nkv)
     lengths = lengths.astype(jnp.int32)
@@ -280,8 +281,7 @@ def decode_attend_paged(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     g = nq // nkv
     if p % block_size:
         raise ValueError(f"pool rows {p} not divisible by {block_size}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     nb = tables.shape[1]
     kb = k.reshape(p // block_size, block_size, nkv, d)
     vb = v.reshape(p // block_size, block_size, nkv, d)

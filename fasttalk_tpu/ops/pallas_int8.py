@@ -23,7 +23,8 @@ Two layouts:
   materialising the transpose.
 
 Single-device path (like ops/pallas_attention.py): under a TP mesh GSPMD
-cannot partition a custom kernel, so the mesh path keeps the XLA matmul.
+cannot partition a custom kernel, so the engine rejects the kernel
+flags on a mesh and the mesh path runs the XLA matmul.
 """
 
 from __future__ import annotations
@@ -35,22 +36,21 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.x ships this dataclass as TPUCompilerParams; newer releases
-# renamed it. Resolve once so the kernels run on both.
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+from fasttalk_tpu.ops.pallas_backend import resolve_interpret
 
 # Per-block VMEM budget for the streamed q block (bytes, int8 elems).
-# Double-buffered by the pipeline: 2x this resides in VMEM. XLA's
-# scoped-vmem limit DEFAULTS to 16 MiB on this toolchain (measured:
-# 8 MiB blocks OOM at 16.84M "limit 16.00M"; nothing in this file sets
-# the flag), so 2 MiB blocks leave room for the accumulator/output
-# while staying large enough to stream at HBM rate.
+# Double-buffered by the pipeline: 2x this resides in VMEM. Nothing in
+# this file raises the compiler's scoped-VMEM limit (16 MiB by
+# default), so 2 MiB blocks leave room for the accumulator/output
+# while staying large enough to stream at HBM rate. Checked on a v5e
+# with JAX 0.9.0 / libtpu 0.0.34: every llama3.2:1b shape compiles and
+# runs at this size (scripts/check_kernels.py).
 _BLOCK_BYTES = 2 * 1024 * 1024
 # Working-set ceiling the supports() estimate checks against (blocks
-# double-buffered + accumulator + output), a margin under the 16 MiB
-# default above; shapes that exceed it (the untied [4096, 128256]
-# lm_head's full-N accumulator) fall back to XLA.
+# double-buffered + accumulator + output), a margin under that 16 MiB
+# default; shapes that exceed it (the untied [4096, 128256] lm_head's
+# full-N accumulator) take the XLA dequant path, and ops/quant.py
+# records that they did.
 _VMEM_BUDGET = 12 * 1024 * 1024
 
 
@@ -94,8 +94,7 @@ def int8_matmul(x: jnp.ndarray, q: jnp.ndarray, s: jnp.ndarray,
     assert k == k2 and s.shape == (n,)
     bk = _row_block(k, n)
     assert bk is not None, (k, n)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     k_blocks = k // bk
 
     return pl.pallas_call(
@@ -109,7 +108,7 @@ def int8_matmul(x: jnp.ndarray, q: jnp.ndarray, s: jnp.ndarray,
         out_specs=pl.BlockSpec((m, n), lambda kb: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((m, n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(x, q, s.reshape(1, n))
@@ -137,8 +136,7 @@ def int8_matmul_t(x: jnp.ndarray, q: jnp.ndarray, s: jnp.ndarray,
     assert d == d2 and s.shape == (v,)
     bv = _row_block(v, d)
     assert bv is not None, (v, d)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
 
     return pl.pallas_call(
         functools.partial(_mm_t_kernel, out_dtype=x.dtype),
@@ -150,7 +148,7 @@ def int8_matmul_t(x: jnp.ndarray, q: jnp.ndarray, s: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((m, bv), lambda vb: (0, vb)),
         out_shape=jax.ShapeDtypeStruct((m, v), x.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(x, q, s.reshape(1, v))
@@ -191,22 +189,22 @@ def supports_t(x_shape, q_shape, itemsize: int = 2) -> bool:
 
 def _row_block4(k: int, n: int, group: int) -> int | None:
     """Unpacked-row block size for the int4 kernel: a multiple of the
-    scale group (so each block owns whole groups), >= 128 (lane-dim
-    floor, see _row_block), dividing ``k``, with the unpacked int8 tile
-    held to half the int8 kernel's block budget — the dequant pipeline
-    (unpack -> cast -> scale-multiply) keeps ~2 extra tiles of that
-    size live in VMEM."""
+    scale group (so each block owns whole groups), >= 256 (the kernel
+    reads x as even/odd halves, so HALF the block is the x operands'
+    lane dimension — the 128-lane floor of _row_block, doubled),
+    dividing ``k``, with the unpacked tile held to the int8 kernel's
+    element budget (the packed bytes streamed are half that)."""
     best = None
     b = group
     while b <= k and k % b == 0:
-        if b >= 128 and b * n <= _BLOCK_BYTES // 2:
+        if b >= 256 and b * n <= _BLOCK_BYTES:
             best = b
         b *= 2
     return best
 
 
-def _mm4_kernel(x_ref, q_ref, s_ref, o_ref, acc_ref, *, k_blocks: int,
-                group: int, out_dtype):
+def _mm4_kernel(x_ref, q_ref, s_ref, o_ref, acc_ref, *,
+                k_blocks: int, half_group: int, out_dtype):
     kb = pl.program_id(0)
 
     @pl.when(kb == 0)
@@ -214,24 +212,33 @@ def _mm4_kernel(x_ref, q_ref, s_ref, o_ref, acc_ref, *, k_blocks: int,
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     # Unpack two's-complement nibbles: packed row j holds original row
-    # 2j in the low nibble, 2j+1 in the high one. int8 ``>>`` is
-    # arithmetic, so ``(b << 4) >> 4`` sign-extends the low nibble.
-    b = q_ref[:].astype(jnp.int8)  # [bk/2, n] packed pairs
-    lo = (b << 4) >> 4
-    hi = b >> 4
-    bkp, n = b.shape
-    w = jnp.stack([lo, hi], axis=1).reshape(2 * bkp, n).astype(x_ref.dtype)
-    # Expand group scales [gpb, n] -> [bk, n] with leading-dim-only
-    # broadcast+reshape (Mosaic-friendly: lane dim untouched). Group
-    # scales vary along K, so the multiply must happen per-tile inside
-    # the accumulation — it cannot factor out like the int8 kernel's
-    # per-N scale.
-    gpb = s_ref.shape[0]
+    # 2j in the low nibble, 2j+1 in the high one. The bytes arrive as
+    # int8 and widen to int32 first — Mosaic has no 8-bit vector
+    # shifts ("failed to legalize arith.shli" on vector<..xi8>). The
+    # widening sign-extends, so ``>> 4`` is already the signed high
+    # nibble and ``(b << 28) >> 28`` sign-extends the low one.
+    b = q_ref[:].astype(jnp.int32)  # [bk/2, n] packed pairs
+    lo = ((b << 28) >> 28).astype(jnp.float32)
+    hi = (b >> 4).astype(jnp.float32)
+    # Both nibbles of a packed row belong to the same scale group (the
+    # group size is even), so one expansion [gpb, n] -> [bk/2, n]
+    # serves both halves: leading-dim-only broadcast+reshape, lane dim
+    # untouched. Group scales vary along K, so the multiply must happen
+    # per-tile inside the accumulation — it cannot factor out like the
+    # int8 kernel's per-N scale.
+    gpb, n = s_ref.shape[1], s_ref.shape[2]
     sexp = jnp.broadcast_to(
-        s_ref[:].astype(x_ref.dtype)[:, None, :],
-        (gpb, group, n)).reshape(gpb * group, n)
-    acc_ref[:] += jax.lax.dot(x_ref[:], w * sexp,
-                              preferred_element_type=jnp.float32)
+        s_ref[0].astype(jnp.float32)[:, None, :],
+        (gpb, half_group, n)).reshape(gpb * half_group, n)
+    # x arrives split into its even and odd columns (by the caller, on
+    # the small operand): that replaces interleaving lo/hi back into
+    # row order, which would be a sublane shuffle of the big one.
+    dt = x_ref.dtype
+    acc_ref[:] += (
+        jax.lax.dot(x_ref[0], (lo * sexp).astype(dt),
+                    preferred_element_type=jnp.float32)
+        + jax.lax.dot(x_ref[1], (hi * sexp).astype(dt),
+                      preferred_element_type=jnp.float32))
 
     @pl.when(kb == k_blocks - 1)
     def _out():
@@ -250,26 +257,31 @@ def int4_matmul(x: jnp.ndarray, q4: jnp.ndarray, s: jnp.ndarray,
     group = k // groups
     bk = _row_block4(k, n, group)
     assert bk is not None, (k, n, group)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     k_blocks = k // bk
+    gpb = bk // group
 
     return pl.pallas_call(
-        functools.partial(_mm4_kernel, k_blocks=k_blocks, group=group,
-                          out_dtype=x.dtype),
+        functools.partial(_mm4_kernel, k_blocks=k_blocks,
+                          half_group=group // 2, out_dtype=x.dtype),
         grid=(k_blocks,),
         in_specs=[
-            pl.BlockSpec((m, bk), lambda kb: (0, kb)),
+            pl.BlockSpec((2, m, bk // 2), lambda kb: (0, 0, kb)),
             pl.BlockSpec((bk // 2, n), lambda kb: (kb, 0)),  # contiguous rows
-            pl.BlockSpec((bk // group, n), lambda kb: (kb, 0)),
+            # Scales ride as [k_blocks, gpb, n] so a block's last two
+            # dims are whole array dims: a (gpb, n) block of the 2-D
+            # array has gpb < 8 sublanes, which the lowering rejects.
+            pl.BlockSpec((1, gpb, n), lambda kb: (kb, 0, 0)),
         ],
         out_specs=pl.BlockSpec((m, n), lambda kb: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((m, n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(x, q4, s)
+    )(jnp.moveaxis(x.reshape(m, kp, 2), 2, 0),  # [2, M, K/2] even | odd
+      jax.lax.bitcast_convert_type(q4, jnp.int8),
+      s.reshape(k_blocks, gpb, n))
 
 
 def supports_q4(x_shape, q4_shape, s_shape, itemsize: int = 2) -> bool:
@@ -286,7 +298,8 @@ def supports_q4(x_shape, q4_shape, s_shape, itemsize: int = 2) -> bool:
     bk = _row_block4(k, n, group)
     if n % 128 != 0 or bk is None:
         return False
-    # Packed block double-buffered (bk//2 * n * 2 = bk*n) + unpacked
-    # int8 + dequantized/scaled tiles + accumulator + x + out.
-    vmem = (2 + 2 * itemsize) * bk * n + 4 * m * n + itemsize * m * (n + k)
+    # Packed block double-buffered (bk//2 * n * 2 = bk*n) + the f32
+    # scale expansion (bk//2 * n * 4) + two dequantized half tiles +
+    # accumulator + x + out.
+    vmem = (3 + itemsize) * bk * n + 4 * m * n + itemsize * m * (n + k)
     return vmem <= _VMEM_BUDGET

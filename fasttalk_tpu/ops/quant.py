@@ -102,6 +102,41 @@ def quantize_params(params: Any) -> Any:
     return out
 
 
+# Which implementation each decode-shaped (T=1) quantized matmul took,
+# recorded while tracing: "<kind> <rows>x<cols> m=<batch>" -> "pallas" |
+# "xla:flag_off" | "xla:unsupported_shape". The per-shape choice below
+# is made from what supports*() can see, so this is the only place the
+# outcome is visible: engines report it (get_model_info) and the chip
+# smoke asserts on it instead of assuming the kernel ran. T>1 blocks
+# (prefill, spec verify) always take XLA by design and are not
+# recorded. Process-wide and keyed by shape: in-process replicas of one
+# model share entries.
+_TRACED_PATHS: dict[str, str] = {}
+
+
+def traced_paths() -> dict[str, str]:
+    """Snapshot of the kernel choices traced so far in this process."""
+    return dict(_TRACED_PATHS)
+
+
+def _kernel_eligible(kind: str, x: jax.Array, wshape: tuple,
+                     flag: bool, supported) -> bool:
+    """True when the T=1 call ``x`` [B, 1, K] should take the Pallas
+    kernel; records the outcome either way. ``supported`` is a
+    zero-arg callable so supports*() only runs when the flag is on."""
+    if x.ndim != 3 or x.shape[1] != 1:
+        return False
+    if not flag:
+        path = "xla:flag_off"
+    elif supported():
+        path = "pallas"
+    else:
+        path = "xla:unsupported_shape"
+    _TRACED_PATHS[f"{kind} {wshape[-2]}x{wshape[-1]} "
+                  f"m={x.shape[0]}"] = path
+    return path == "pallas"
+
+
 def matmul(x: jax.Array, w: Any, pallas_ok: bool = False,
            pallas_int4: bool = False) -> jax.Array:
     """``x @ w`` for a plain or quantized weight leaf.
@@ -116,14 +151,18 @@ def matmul(x: jax.Array, w: Any, pallas_ok: bool = False,
     routes T=1 decode to the in-register unpacking kernel instead.
     """
     if isinstance(w, dict):
-        if "q4" in w:
-            if pallas_int4 and x.ndim == 3 and x.shape[1] == 1:
-                from fasttalk_tpu.ops.pallas_int8 import (int4_matmul,
-                                                          supports_q4)
+        from fasttalk_tpu.ops import pallas_int8 as pk
 
-                if supports_q4((x.shape[0], x.shape[2]), w["q4"].shape,
-                               w["s"].shape, jnp.dtype(x.dtype).itemsize):
-                    return int4_matmul(x[:, 0], w["q4"], w["s"])[:, None]
+        itemsize = jnp.dtype(x.dtype).itemsize
+        if "q4" in w:
+            if _kernel_eligible(
+                    "int4", x,
+                    (2 * w["q4"].shape[-2], w["q4"].shape[-1]),
+                    pallas_int4,
+                    lambda: pk.supports_q4(
+                        (x.shape[0], x.shape[2]), w["q4"].shape,
+                        w["s"].shape, itemsize)):
+                return pk.int4_matmul(x[:, 0], w["q4"], w["s"])[:, None]
             from fasttalk_tpu.quantization.int4 import unpack_int4
 
             group = (2 * w["q4"].shape[-2]) // w["s"].shape[-2]
@@ -135,23 +174,20 @@ def matmul(x: jax.Array, w: Any, pallas_ok: bool = False,
             # same contiguous row-block kernel as the tied embedding
             # streams it at HBM rate (ADVICE r3 — the [D, V] layout's
             # full-V accumulator busted VMEM and forced XLA dequant).
-            if pallas_ok and x.ndim == 3 and x.shape[1] == 1:
-                from fasttalk_tpu.ops.pallas_int8 import (int8_matmul_t,
-                                                          supports_t)
-
-                if supports_t((x.shape[0], x.shape[2]), w["qt"].shape,
-                              jnp.dtype(x.dtype).itemsize):
-                    return int8_matmul_t(x[:, 0], w["qt"], w["s"])[:, None]
+            if _kernel_eligible(
+                    "int8_t", x, w["qt"].shape, pallas_ok,
+                    lambda: pk.supports_t((x.shape[0], x.shape[2]),
+                                          w["qt"].shape, itemsize)):
+                return pk.int8_matmul_t(x[:, 0], w["qt"], w["s"])[:, None]
             out = jax.lax.dot_general(
                 x, w["qt"].astype(x.dtype),
                 (((x.ndim - 1,), (1,)), ((), ())))
             return out * w["s"].astype(x.dtype)
-        if pallas_ok and x.ndim == 3 and x.shape[1] == 1:
-            from fasttalk_tpu.ops.pallas_int8 import int8_matmul, supports
-
-            if supports((x.shape[0], x.shape[2]), w["q"].shape,
-                        jnp.dtype(x.dtype).itemsize):
-                return int8_matmul(x[:, 0], w["q"], w["s"])[:, None]
+        if _kernel_eligible(
+                "int8", x, w["q"].shape, pallas_ok,
+                lambda: pk.supports((x.shape[0], x.shape[2]),
+                                    w["q"].shape, itemsize)):
+            return pk.int8_matmul(x[:, 0], w["q"], w["s"])[:, None]
         return (x @ w["q"].astype(x.dtype)) * w["s"].astype(x.dtype)
     return x @ w
 
@@ -174,13 +210,14 @@ def matmul_tied(x: jax.Array, emb: Any, pallas_ok: bool = False) -> jax.Array:
     transpose (ops/pallas_int8.py int8_matmul_t).
     """
     if isinstance(emb, dict):
-        if pallas_ok and x.ndim == 3 and x.shape[1] == 1:
-            from fasttalk_tpu.ops.pallas_int8 import (int8_matmul_t,
-                                                      supports_t)
+        from fasttalk_tpu.ops import pallas_int8 as pk
 
-            if supports_t((x.shape[0], x.shape[2]), emb["q"].shape,
-                          jnp.dtype(x.dtype).itemsize):
-                return int8_matmul_t(x[:, 0], emb["q"], emb["s"])[:, None]
+        if _kernel_eligible(
+                "int8_t", x, emb["q"].shape, pallas_ok,
+                lambda: pk.supports_t((x.shape[0], x.shape[2]),
+                                      emb["q"].shape,
+                                      jnp.dtype(x.dtype).itemsize)):
+            return pk.int8_matmul_t(x[:, 0], emb["q"], emb["s"])[:, None]
         return (x @ emb["q"].astype(x.dtype).T) * emb["s"].astype(x.dtype)
     return x @ emb.T
 

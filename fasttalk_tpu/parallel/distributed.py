@@ -8,14 +8,19 @@ XLA collectives over ICI within a slice (emitted by GSPMD from the
 sharding rules in parallel/sharding.py), and the JAX distributed runtime
 over DCN across hosts — which this module initialises.
 
-On a multi-host TPU slice (GKE / queued resources), ``initialize()``
-with no env overrides lets JAX auto-discover the coordinator from the
-TPU metadata. Elsewhere (CPU fleets, explicit setups), the standard
-``TPU_COORDINATOR_ADDR`` / ``TPU_NUM_PROCESSES`` / ``TPU_PROCESS_ID``
-env vars drive it. After initialisation, ``jax.devices()`` spans every
-host and the meshes built by parallel/mesh.py place DP/SP axes across
-DCN and TP within ICI (mesh axis order is chosen so the innermost axis
-— "tp" — maps to the fastest links).
+On a multi-host TPU slice (GKE / queued resources) — recognised by
+``TPU_WORKER_HOSTNAMES`` listing more than one worker, or a multislice
+coordinator — ``initialize()`` with no env overrides lets JAX
+auto-discover the coordinator from the TPU metadata. Elsewhere (CPU
+fleets, explicit setups), the standard ``TPU_COORDINATOR_ADDR`` /
+``TPU_NUM_PROCESSES`` / ``TPU_PROCESS_ID`` env vars drive it. A single
+host never goes through discovery: TPU VM images set
+``TPU_WORKER_HOSTNAMES=localhost`` on one-host machines too, and
+discovery on a machine with no metadata server or network is a hang or
+a long timeout at start. After initialisation, ``jax.devices()`` spans
+every host and the meshes built by parallel/mesh.py place DP/SP axes
+across DCN and TP within ICI (mesh axis order is chosen so the
+innermost axis — "tp" — maps to the fastest links).
 """
 
 from __future__ import annotations
@@ -33,43 +38,32 @@ def maybe_initialize() -> bool:
     """Initialise the JAX distributed runtime when configured.
 
     Returns True when running (or already running) multi-process.
-    No-ops when neither env configuration nor a TPU pod environment is
-    present, so single-host serving never pays the coordinator setup.
+    No-ops (without importing jax) when neither env configuration nor a
+    multi-host TPU environment is present, so single-host serving never
+    pays the coordinator setup. A configured or detected multi-host
+    environment that fails to initialise raises: continuing single-host
+    would serve a fraction of the slice and look healthy.
     """
     global _initialized
     if _initialized:
         return True
-    import jax
-
     coordinator = os.environ.get("TPU_COORDINATOR_ADDR", "")
     nprocs = os.environ.get("TPU_NUM_PROCESSES", "")
     pid = os.environ.get("TPU_PROCESS_ID", "")
+    workers = [h for h in os.environ.get(
+        "TPU_WORKER_HOSTNAMES", "").split(",") if h.strip()]
     if coordinator and nprocs:
-        # Explicitly configured: a failure here is a misconfiguration
-        # and must be fatal.
-        try:
-            jax.distributed.initialize(
-                coordinator_address=coordinator,
-                num_processes=int(nprocs),
-                process_id=int(pid or 0))
-        except Exception as e:
-            log.error(f"jax.distributed.initialize failed: {e}")
-            raise
-    elif os.environ.get("TPU_WORKER_HOSTNAMES") or \
+        kwargs = dict(coordinator_address=coordinator,
+                      num_processes=int(nprocs),
+                      process_id=int(pid or 0))
+    elif len(workers) > 1 or \
             os.environ.get("MEGASCALE_COORDINATOR_ADDRESS"):
-        # Looks like a TPU pod/multislice environment: try
-        # auto-discovery, but degrade to single-host rather than fail —
-        # the env hint also appears on single-host setups, and the
-        # backend may already be initialised by an earlier jax call.
-        try:
-            jax.distributed.initialize()
-        except Exception as e:
-            log.warning(
-                f"distributed auto-init unavailable ({e}); continuing "
-                "single-host")
-            return False
+        kwargs = {}  # pod / multislice: JAX discovers the coordinator
     else:
         return False
+    import jax
+
+    jax.distributed.initialize(**kwargs)
     _initialized = True
     log.info("distributed runtime up",
              process_index=jax.process_index(),

@@ -109,13 +109,6 @@ def run_test(args: argparse.Namespace) -> int:
 
 
 def run_websocket(args: argparse.Namespace) -> int:
-    # Multi-host first: jax.distributed must initialise before ANY jax
-    # call (Config's device detection touches the backend). No-op
-    # without cluster env.
-    from fasttalk_tpu.parallel.distributed import maybe_initialize
-
-    maybe_initialize()
-
     from fasttalk_tpu.serving.launcher import ServerLauncher
     from fasttalk_tpu.utils.config import Config
     from fasttalk_tpu.utils.logger import configure_logging, get_logger

@@ -4,10 +4,12 @@ BENCH_r*.json trajectory.
 
 Every growth round commits its bench result as ``BENCH_rNN.json``
 (``{"n", "cmd", "rc", "tail", "parsed"}`` where ``parsed`` is the one
-JSON line bench.py printed). That trajectory is the repo's performance
-memory — r01 1172.8 -> r05 2526.2 tok/s — but nothing READ it: a
+JSON line bench.py printed). Nothing READ that trajectory: a
 regression only surfaced when a human eyeballed two files. This gate
-closes the loop:
+closes the loop. (The committed set is empty since PR 21 removed the
+records taken before PR 1 on another attach of the chip — a chip run
+of today's code must not be held against them; with no history for a
+mode the gate passes as a baseline.)
 
     python bench.py > /tmp/fresh.json
     python scripts/bench_compare.py /tmp/fresh.json
@@ -221,9 +223,12 @@ def smoke(pattern: str) -> int:
     absolute-bound modes must gate both directions."""
     history = load_history(pattern)
     if not history:
-        print("bench_compare --smoke: no committed BENCH_r*.json "
-              "found", file=sys.stderr)
-        return 1
+        # No committed records: the smoke tests the GATE, so a made-up
+        # two-entry trajectory serves (no device produced these values).
+        history = [(f"synthetic_r0{i}",
+                    {"metric": "WebSocket output tok/s, synthetic",
+                     "value": v, "unit": "tok/s"})
+                   for i, v in ((1, 1000.0), (2, 1010.0))]
     latest = dict(history[-1][1])
     rc = compare(latest, history)
     if rc != 0:

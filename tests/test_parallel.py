@@ -508,6 +508,17 @@ def test_distributed_init_noop_without_config(monkeypatch):
                 "MEGASCALE_COORDINATOR_ADDRESS"):
         monkeypatch.delenv(var, raising=False)
     assert distributed.maybe_initialize() is False
+    # A single-host TPU VM image sets this too (the chip tool's machine
+    # does): one worker is not a pod, and discovery on a machine with
+    # no metadata server is a hang or a long timeout at start.
+    import jax
+
+    def no_discovery(*a, **kw):
+        raise AssertionError("single host went through discovery")
+
+    monkeypatch.setattr(jax.distributed, "initialize", no_discovery)
+    monkeypatch.setenv("TPU_WORKER_HOSTNAMES", "localhost")
+    assert distributed.maybe_initialize() is False
     info = distributed.process_info()
     assert info["process_count"] == 1
     assert info["initialized"] is False

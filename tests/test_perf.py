@@ -188,6 +188,41 @@ class TestPerfLedger:
         assert s["ceiling_tok_s"] == pytest.approx(64.0)
         assert s["frac_of_ceiling"] == pytest.approx(0.25)
 
+    def test_peaks_count_the_engines_devices_not_the_hosts(self,
+                                                           monkeypatch):
+        """A one-chip engine on a four-chip host: the roofline
+        denominators are one chip's peaks (they used to be multiplied
+        by len(jax.local_devices()) — a 4x denominator)."""
+        import jax
+
+        class FakeDev:
+            platform = "tpu"
+            device_kind = "TPU v5 lite"
+
+        host = [FakeDev() for _ in range(4)]
+        monkeypatch.setattr(jax, "local_devices", lambda: host)
+        monkeypatch.setattr(jax, "devices", lambda: host)
+        monkeypatch.delenv("PERF_PEAK_TFLOPS", raising=False)
+        monkeypatch.delenv("PERF_PEAK_HBM_GBPS", raising=False)
+        tr = Tracer(enabled=True)
+        _step(tr, 100.0, 101.0)
+        led = _ledger(tr)
+        led.bind_model(TINY, num_slots=4, dtype="bfloat16",
+                       devices=host[:1])
+        rep = led.report(now=101.0)
+        assert rep["mfu"]["device"] == "TPU v5 lite"
+        assert rep["mfu"]["peak_tflops"] == pytest.approx(197.0)
+        assert led._peak_hbm() == (pytest.approx(819.0), "TPU v5 lite")
+        # A tp=4 mesh engine on the same host sums its four devices.
+        led.bind_model(TINY, num_slots=4, dtype="bfloat16", devices=host)
+        assert led.report(now=101.0)["mfu"]["peak_tflops"] == \
+            pytest.approx(4 * 197.0)
+        # A kind the table does not know: null, never a default.
+        FakeDev.device_kind = "TPU v9 imaginary"
+        led.bind_model(TINY, num_slots=4, dtype="bfloat16",
+                       devices=host[:1])
+        assert led.report(now=101.0)["mfu"]["peak_tflops"] is None
+
     def test_ceiling_null_without_peak(self):
         # CPU / unknown device: nulls, never a made-up ceiling.
         tr = Tracer(enabled=True)

@@ -4,7 +4,8 @@ import time
 
 import pytest
 
-from fasttalk_tpu.utils.config import Config, detect_compute_device
+from fasttalk_tpu.utils.config import (ComputeDeviceError, Config,
+                                       detect_compute_device)
 from fasttalk_tpu.utils.errors import (
     CircuitBreaker,
     CircuitBreakerOpen,
@@ -24,7 +25,7 @@ class TestConfig:
         monkeypatch.delenv("COMPUTE_DEVICE", raising=False)
         cfg = Config()
         assert cfg.llm_provider == "tpu"
-        assert cfg.compute_device in ("tpu", "cuda", "cpu", "mps")
+        assert cfg.compute_device == "auto"  # the request, unresolved
         assert cfg.decode_slots == 16
 
     def test_env_overrides(self, monkeypatch):
@@ -60,9 +61,57 @@ class TestConfig:
         monkeypatch.setenv("COMPUTE_DEVICE", "cpu")
         assert detect_compute_device() == "cpu"
 
-    def test_device_detection_falls_back_on_bogus(self, monkeypatch):
+    def test_device_detection_rejects_bogus(self, monkeypatch):
         monkeypatch.setenv("COMPUTE_DEVICE", "quantum")
-        assert detect_compute_device() in ("tpu", "cuda", "cpu", "mps")
+        with pytest.raises(ComputeDeviceError, match="quantum"):
+            detect_compute_device()
+        with pytest.raises(ValueError, match="compute_device"):
+            Config()
+
+    def test_auto_detection_picks_an_available_device(self, monkeypatch):
+        monkeypatch.delenv("COMPUTE_DEVICE", raising=False)
+        assert detect_compute_device() == "cpu"  # conftest: JAX on cpu
+
+    def test_explicit_tpu_without_a_tpu_is_a_named_error(self,
+                                                         monkeypatch):
+        """COMPUTE_DEVICE=tpu on a machine whose JAX sees no TPU must
+        not resolve to another device (it used to serve on the CPU)."""
+        monkeypatch.setenv("COMPUTE_DEVICE", "tpu")
+        with pytest.raises(ComputeDeviceError, match="no tpu device"):
+            detect_compute_device()
+
+    def test_backend_failure_is_carried_not_swallowed(self, monkeypatch):
+        """A backend that is present but unusable ("TPU already in use
+        by another process") surfaces with its own message, also in
+        auto mode — never as a quiet 'cpu'."""
+        import jax
+
+        def boom():
+            raise RuntimeError("TPU is already in use by pid 1234")
+
+        monkeypatch.setattr(jax, "devices", boom)
+        for requested in ("tpu", "auto"):
+            with pytest.raises(ComputeDeviceError,
+                               match="already in use") as ei:
+                detect_compute_device(requested)
+            assert isinstance(ei.value.__cause__, RuntimeError)
+
+    def test_config_construction_touches_no_backend(self):
+        """Building a Config — in an orchestrating parent, or `main.py
+        config --show` beside a running server — must not claim the
+        chip: it does not even import jax."""
+        import subprocess
+        import sys
+
+        code = ("import sys\n"
+                "from fasttalk_tpu.utils.config import Config\n"
+                "cfg = Config(); cfg.to_dict()\n"
+                "assert 'jax' not in sys.modules, 'Config imported jax'\n")
+        p = subprocess.run([sys.executable, "-c", code], text=True,
+                           capture_output=True, timeout=60,
+                           env={**__import__("os").environ,
+                                "COMPUTE_DEVICE": "tpu"})
+        assert p.returncode == 0, p.stderr[-1500:]
 
     def test_presets(self):
         cfg = Config()
